@@ -17,6 +17,9 @@ _RECORDS: List[dict] = []
 
 
 def timeit(fn, *, warmup=1, iters=3):
+    """Mean wall seconds per call of ``fn`` after ``warmup`` calls.  JAX
+    dispatch is asynchronous: ``fn`` must end in ``block_until_ready`` (or
+    pull its result to the host), or this measures only the enqueue."""
     for _ in range(warmup):
         fn()
     t0 = time.perf_counter()
@@ -25,12 +28,30 @@ def timeit(fn, *, warmup=1, iters=3):
     return (time.perf_counter() - t0) / iters
 
 
+def device_stamp() -> dict:
+    """The device a measurement ran on, as JAX reports it."""
+    import jax
+
+    dev = jax.devices()[0]
+    return {
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
+        "device_count": len(jax.devices()),
+    }
+
+
 def emit(name: str, us_per_call: float, derived: str = "", **metrics):
     """Print one CSV result line and record it (plus structured ``metrics``
-    key/values) for the JSON dump."""
+    key/values and the device stamp) for the JSON dump."""
     print(f"{name},{us_per_call:.2f},{derived}")
     _RECORDS.append(
-        {"name": name, "us_per_call": us_per_call, "derived": derived, **metrics}
+        {
+            "name": name,
+            "us_per_call": us_per_call,
+            "derived": derived,
+            **metrics,
+            **device_stamp(),
+        }
     )
 
 

@@ -33,4 +33,7 @@ def main(quick=False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     main()

@@ -110,7 +110,7 @@ def main(quick=False):
     t = timeit(lambda: jax.block_until_ready(ref(keys, vals, qs)))
     emit("kernel.leaf_probe.ref_xla", t * 1e6, f"batch={bsz}")
     t = timeit(
-        lambda: jax.block_until_ready(leaf_probe_pallas(keys, vals, qs, interpret=True)),
+        lambda: jax.block_until_ready(leaf_probe_pallas(keys, vals, qs)),
     )
     emit("kernel.leaf_probe.pallas_interp", t * 1e6, "interpret-mode (structural)")
 
@@ -140,4 +140,7 @@ def main(quick=False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     main()

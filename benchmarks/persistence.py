@@ -220,4 +220,7 @@ def main(quick=False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     main()

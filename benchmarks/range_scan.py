@@ -1,6 +1,6 @@
 """Range-scan microbenchmark: batched ``scan_round`` throughput vs span and
 batch size, plus the ``kernels/range_scan`` Pallas kernel vs its jnp ref on
-the gather hot loop (int32 device keys, interpret mode on CPU)."""
+the gather hot loop (int32 device keys; interpret mode on the CPU only)."""
 from __future__ import annotations
 
 import os
@@ -52,7 +52,7 @@ def _bench_kernel(quick=False):
     hi = keys[:, 3 * n // 4].astype(np.int32)
     args = tuple(jnp.asarray(x) for x in (keys, vals, lo, hi))
     for name, fn in (
-        ("pallas", lambda: range_scan_pallas(*args, cap=cap, interpret=True)[0].block_until_ready()),
+        ("pallas", lambda: range_scan_pallas(*args, cap=cap)[0].block_until_ready()),
         ("ref", lambda: range_scan_ref(*args, cap)[0].block_until_ready()),
     ):
         dt = timeit(fn, warmup=1, iters=2 if quick else 5)
@@ -65,4 +65,7 @@ def main(quick=False):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     main()

@@ -44,6 +44,8 @@ import os
 import sys
 import traceback
 
+from repro.compile_cache import use_persistent_cache
+
 # metrics compared under the relative tolerance (higher is better);
 # integral metrics compared exactly (deterministic for a seeded workload:
 # round counts, and the durable layer's commit/fsync counts).
@@ -108,6 +110,7 @@ def main() -> None:
         "tolerates machine variance; tighten locally for perf work)",
     )
     args = ap.parse_args()
+    use_persistent_cache()
 
     baselines = []
     for path in args.check or []:
@@ -149,15 +152,17 @@ def main() -> None:
 
     print("name,us_per_call,derived")
     section_records = {}
+    raised = []
     for name, fn in sections.items():
         if args.only and name != args.only:
             continue
         print(f"# --- {name} ---")
         try:
             fn(quick=args.quick)
-        except Exception as e:  # noqa: BLE001
+        except Exception as e:  # noqa: BLE001 — report, run the rest, fail at exit
             print(f"{name}.ERROR,0.0,{type(e).__name__}:{e}")
             traceback.print_exc(file=sys.stderr)
+            raised.append(name)
         records = drain_records()
         if records:
             section_records[name] = records
@@ -184,6 +189,10 @@ def main() -> None:
                 print(f"# CHECK FAIL {msg}")
             sys.exit(1)
         print(f"# --- check: OK ({len(baselines)} baseline(s), tol={args.check_tol}) ---")
+
+    if raised:
+        print(f"# --- sections raised: {', '.join(raised)} ---")
+        sys.exit(1)
 
     # roofline summary (from the dry-run artifact, if present)
     if args.only in (None, "roofline"):

@@ -128,6 +128,9 @@ def main(quick: bool = False, group_commit_every: int = 4):
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--group-commit-every", type=int, default=4,

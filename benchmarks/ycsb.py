@@ -616,6 +616,9 @@ def main(quick=False, workload="A", scan_path="both", shards=0, narrow=False,
 
 
 if __name__ == "__main__":
+    from repro.compile_cache import use_persistent_cache
+
+    use_persistent_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", default="A", choices=["A", "E", "a", "e"])
     ap.add_argument(

@@ -159,6 +159,17 @@ def grow_pool(state: TreeState, pad_n: int, axis: int = 0) -> TreeState:
     return TreeState(**out)
 
 
+def wave_width(capacity: int) -> int:
+    """Pad width of a structural (split / underfull) wave: 64 nodes, or
+    1/256 of the pool once that is larger.  Every wave is a whole-pool
+    program plus whole-pool pulls to the host, so at deployment size a
+    round's thousands of splits must take a handful of waves, not
+    hundreds; small pools keep 64.  The width changes only with the
+    capacity, which recompiles every phase anyway, so it adds no program
+    variants."""
+    return max(64, capacity >> 8)
+
+
 def make_tree(cfg: TreeConfig) -> TreeState:
     # Pool has capacity+1 rows: the last row is a write-off SCRATCH row that
     # absorbs all masked-out scatter lanes.  Routing inactive lanes to a
@@ -349,9 +360,16 @@ def apply_net_ops(
 
 def _alloc_ids(state: TreeState, k: int) -> jax.Array:
     """ids of k free nodes (deterministic: lowest ids first).  The last
-    pool row (scratch) is never handed out."""
-    order = jnp.argsort(state.alloc[:-1], stable=True)  # False (free) first
-    return order[:k].astype(jnp.int32)
+    pool row (scratch) is never handed out; callers keep ≥ k rows free
+    (``_ensure_capacity``), and any shortfall maps to scratch.  The j-th
+    free id is where the running free count first reaches j + 1: one
+    cumsum over the pool and k binary searches.  (A whole-pool argsort
+    here cost every split wave a sort of the pool in compile and run time,
+    and ``nonzero`` a scatter of the whole pool.)"""
+    scratch = state.alloc.shape[0] - 1
+    n_free = jnp.cumsum(~state.alloc[:-1], dtype=jnp.int32)
+    ids = jnp.searchsorted(n_free, jnp.arange(1, k + 1, dtype=jnp.int32))
+    return jnp.where(ids < scratch, ids, scratch).astype(jnp.int32)
 
 
 def _refresh_child_links(state: TreeState, parents: jax.Array, cfg: TreeConfig) -> TreeState:
@@ -928,7 +946,7 @@ class ABTree(RegistryBackedCounters):
         # form.  Implies narrow_scan.
         self.narrow = narrow
         self.narrow_scan = narrow_scan or narrow
-        self._wave_w = 64  # pad width for structural waves (recompile-bounded)
+        self._wave_w = wave_width(cfg.capacity)  # structural-wave pad width
         # durable layer hook: OCC durability commits after EVERY sub-round
         # (each sub-round's returns causally follow the previous one — the
         # batched analog of the paper's per-update flush+fence); Elim
@@ -1056,16 +1074,13 @@ class ABTree(RegistryBackedCounters):
     def items(self) -> dict:
         """Host-side snapshot of the dictionary contents (sorted by key)."""
         s = self.state
-        keys = np.asarray(s.keys)
-        vals = np.asarray(s.vals)
         leaf = np.asarray(s.is_leaf) & np.asarray(s.alloc)
-        out = {}
-        for nid in np.nonzero(leaf)[0]:
-            for j in range(self.cfg.b):
-                k = int(keys[nid, j])
-                if k != int(EMPTY):
-                    out[k] = int(vals[nid, j])
-        return dict(sorted(out.items()))
+        keys = np.asarray(s.keys)[leaf].ravel()
+        vals = np.asarray(s.vals)[leaf].ravel()
+        live = keys != int(EMPTY)
+        keys, vals = keys[live], vals[live]
+        order = np.argsort(keys, kind="stable")
+        return dict(zip(keys[order].tolist(), vals[order].tolist()))
 
     def take_dirty(self) -> np.ndarray:
         """Node ids dirtied since the last durable commit (then reset)."""
@@ -1092,7 +1107,9 @@ class ABTree(RegistryBackedCounters):
         wave's allocation (``_alloc_ids(state, 2w)`` slices 2w rows
         unconditionally), which tiny ``capacity`` configs would violate."""
         need = 2 * need_nodes + 4 * self.cfg.max_height + 2 * self._wave_w + 8
-        n_alloc = int(jnp.sum(self.state.alloc))
+        # the stacked form is the one the engine keeps current; reading
+        # ``state`` would slice an unstacked copy of every pool array
+        n_alloc = int(jnp.sum(self.stacked.alloc))
         cap = self.cfg.capacity
         if cap - n_alloc >= need:
             return
@@ -1101,6 +1118,7 @@ class ABTree(RegistryBackedCounters):
     def _grow(self, new_cap: int):
         self.state = grow_pool(self.state, new_cap - self.cfg.capacity, axis=0)
         self.cfg = self.cfg._replace(capacity=new_cap)
+        self._wave_w = wave_width(new_cap)
 
 
 # ----------------------------------------------------------------------------

@@ -116,6 +116,7 @@ from repro.core.abtree import (
     TreeState,
     grow_pool,
     make_tree,
+    wave_width,
 )
 from repro.obs.metrics import (
     MetricsRegistry,
@@ -172,7 +173,11 @@ class ABForest(RegistryBackedCounters):
             lo, hi = key_space if key_space is not None else (0, 1 << 63)
             assert hi - lo >= self.n_shards, "key_space too small for n_shards"
             step = (hi - lo) // self.n_shards
-            splits = lo + step * np.arange(1, self.n_shards, dtype=np.int64)
+            # Python ints: with one shard the step is the whole 63-bit
+            # domain, which does not fit an int64 array operand
+            splits = np.array(
+                [lo + step * i for i in range(1, self.n_shards)], np.int64
+            )
         self._splits = splits.astype(np.int64)
         self._rebuild_bounds()
         self.state: TreeState = _stack_states(
@@ -181,7 +186,7 @@ class ABForest(RegistryBackedCounters):
         self.max_keys_per_shard = max_keys_per_shard
         self._in_split = False
         self._scan_active = 0  # defers shard splits while a scan is in flight
-        self._wave_w = 64  # pad width for structural waves (recompile-bounded)
+        self._wave_w = wave_width(cfg.capacity)  # structural-wave pad width
         self._scan_frontier = 8  # leaf-frontier pad width (doubles on overflow)
         # optimistic-reader hook, as on ABTree: called between a scan's
         # gather and its per-shard version validation (models update rounds
@@ -686,6 +691,7 @@ class ABForest(RegistryBackedCounters):
         # node axis is 1 on the stacked state (axis 0 is the shard axis)
         self.state = grow_pool(self.state, new_cap - self.cfg.capacity, axis=1)
         self.cfg = self.cfg._replace(capacity=new_cap)
+        self._wave_w = wave_width(new_cap)
 
 
 def check_forest_invariants(forest: ABForest) -> None:

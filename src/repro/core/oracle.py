@@ -88,7 +88,12 @@ class DictOracle:
         device scan) or None on point lanes; a range lane's ``results``
         entry is its match count and ``found`` ⇔ non-empty.
         """
-        snapshot = sorted(self.d.items())
+        # round-start snapshot: sorted keys + a frozen copy of the values,
+        # so each range lane is two binary searches, not a dictionary walk
+        snap_keys = snap_vals = None
+        if any(int(op) == OP_RANGE for op in ops):
+            snap_keys = np.sort(np.fromiter(self.d, np.int64, len(self.d)))
+            snap_vals = dict(self.d)
         results: List[int] = []
         found: List[bool] = []
         scans: List[Optional[List[Tuple[int, int]]]] = []
@@ -98,9 +103,12 @@ class DictOracle:
                 if v < 0:
                     raise ValueError(f"malformed OP_RANGE lane: negative span {v}")
                 lo, hi = k, k + v
-                items = [(kk, vv) for kk, vv in snapshot if lo <= kk < hi]
+                a, e = np.searchsorted(
+                    snap_keys, [lo, min(hi, _EMPTY)], side="left"
+                )
                 if cap is not None:
-                    items = items[:cap]
+                    e = min(e, a + cap)
+                items = [(kk, snap_vals[kk]) for kk in snap_keys[a:e].tolist()]
                 scans.append(items)
                 results.append(len(items))
                 found.append(bool(items))

@@ -353,20 +353,26 @@ def _phase_search_combine(state: TreeState, batch, cfg: TreeConfig, narrow: bool
     ops, keys, vals = batch
     bsz = ops.shape[0]
     sort_keys = jnp.where(ops == elim.OP_NOP, EMPTY, keys)
-    perm = jnp.argsort(sort_keys, stable=True)
-    inv = jnp.argsort(perm, stable=True)
-    ks = sort_keys[perm]
+    # stable key sort carrying an int32 arrival index (argsort would carry
+    # an int64 iota under x64, which doubles the TPU sort's compile time)
+    ks, perm = jax.lax.sort(
+        (sort_keys, jnp.arange(bsz, dtype=jnp.int32)), num_keys=1, is_stable=True
+    )
     os_ = ops[perm]
     vs = vals[perm]
-    arrival = perm.astype(jnp.int32)
+    arrival = perm
 
     seg_head = _segment_starts(ks)
     leaf_ids, found, slot, val0 = _search_leaves(state, cfg, ks, narrow)
 
     res = elim.eliminate_batch(os_, vs, seg_head, found, jnp.where(found, val0, 0))
     rets_sorted = elim.op_return_values(os_, res, NOTFOUND)
-    results = rets_sorted[inv]
-    found_out = (rets_sorted != NOTFOUND)[inv]
+    # back to arrival order: a scatter through the permutation (inverting
+    # it with a second sort costs a sort's compile and run time)
+    results = jnp.zeros_like(rets_sorted).at[perm].set(
+        rets_sorted, unique_indices=True
+    )
+    found_out = results != NOTFOUND
 
     stats = state.stats._replace(
         searches=state.stats.searches + jnp.int64(bsz),

@@ -13,11 +13,14 @@ in a single MXU-friendly (G, block_k) score tile.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -77,7 +80,7 @@ def decode_attention_pallas(
     *,
     sm_scale: float | None = None,
     block_k: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     b, h, d = q.shape
     kh, s = k.shape[1], k.shape[2]
@@ -122,6 +125,6 @@ def decode_attention_pallas(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b * kh, group, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(kv_len, qr, kr, vr)
     return out.reshape(b, h, d)

@@ -1,6 +1,8 @@
 """Public wrapper for decode attention."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 
 from repro.kernels.decode_attention.kernel import decode_attention_pallas
@@ -14,7 +16,7 @@ def decode_attention(
     kv_len=None,
     *,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     block_k: int = 256,
 ):
     if use_pallas:

@@ -18,11 +18,14 @@ where "insert/delete" become "accumulate/clear" on embedding rows.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 # op codes (match core.elimination)
 OP_NOP, OP_FIND, OP_INSERT, OP_DELETE = 0, 1, 2, 3
@@ -155,7 +158,7 @@ def elim_combine_pallas(
     val0: jax.Array,  # (B,) int32
     *,
     tile: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     b = ops.shape[0]
     pad = (-b) % tile
@@ -176,7 +179,7 @@ def elim_combine_pallas(
         out_specs=[spec] * 4,
         out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.int32)] * 4,
         scratch_shapes=[pltpu.VMEM((1, 5), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(col(ops), col(vals), col(seg_head), col(present0), col(val0))
     bp, bv, ap, av = (o[:b, 0] for o in outs)
     return bp.astype(bool), bv, ap.astype(bool), av

@@ -1,6 +1,8 @@
 """Public wrapper for the elimination combine."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 
 from repro.kernels.elim_combine.kernel import elim_combine_pallas
@@ -15,7 +17,7 @@ def elim_combine(
     val0: jax.Array,
     *,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
     tile: int = 256,
 ):
     """Segmented publishing-elimination fold.  Returns

@@ -15,11 +15,14 @@ block (≤ 256 for all assigned archs).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 NEG_INF = -1e30
 
@@ -101,7 +104,7 @@ def flash_attention_pallas(
     sm_scale: float | None = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     b, h, s, d = q.shape
     kh = k.shape[1]
@@ -156,6 +159,6 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qr, kr, vr)
     return out.reshape(b, h, sq, d)[:, :, :s, :]

@@ -2,10 +2,10 @@
 
 The Pallas kernel is forward-only; for training we register the oracle's
 VJP so gradients are exact while the forward pays kernel cost.  On real TPU
-hardware the flash backward kernel would replace it; on this CPU container
-the ref path is used in train_step anyway (use_pallas=False default in
-model configs) and the kernel is exercised in interpret mode by tests and
-benchmarks."""
+hardware the flash backward kernel would replace it; the ref path is used
+in train_step anyway (use_pallas=False default in model configs).  The
+kernel runs in interpret mode only on the CPU backend (tests and
+benchmarks there); see ``repro.kernels.interpret_mode``."""
 from __future__ import annotations
 
 import functools
@@ -19,7 +19,7 @@ from repro.kernels.flash_attention.ref import attention_ref
 @functools.partial(
     jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6)
 )
-def flash_attention(q, k, v, causal=True, window=0, sm_scale=None, interpret=True):
+def flash_attention(q, k, v, causal=True, window=0, sm_scale=None, interpret=None):
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, sm_scale=sm_scale, interpret=interpret
     )

@@ -16,11 +16,13 @@ keys are split hi/lo by ops.py when needed).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
 
 
 def _probe_kernel(leaf_keys_ref, leaf_vals_ref, query_ref, slot_ref, val_ref, *, b: int):
@@ -49,7 +51,7 @@ def leaf_probe_pallas(
     queries: jax.Array,  # (B,) int32
     *,
     block_b: int = 256,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     bsz, b = leaf_keys.shape
     pad = (-bsz) % block_b
@@ -76,6 +78,6 @@ def leaf_probe_pallas(
             pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
         ],
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(leaf_keys, leaf_vals, queries[:, None])
     return slot[:bsz, 0], val[:bsz, 0]

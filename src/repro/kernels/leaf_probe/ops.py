@@ -5,6 +5,8 @@ then runs the Pallas probe (or the jnp oracle when use_pallas=False).
 the TPU-native encoding of the paper's 8-byte keys (DESIGN.md §2)."""
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -18,7 +20,7 @@ def leaf_probe(
     queries: jax.Array,
     *,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     if use_pallas:
         return leaf_probe_pallas(leaf_keys, leaf_vals, queries, interpret=interpret)
@@ -31,7 +33,7 @@ def leaf_probe_i64(
     queries64: jax.Array,  # (B,) int64
     *,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Probe 64-bit keys via hi/lo split: slot matches iff both halves match.
     Returns (slot, val) with slot = -1 when absent."""

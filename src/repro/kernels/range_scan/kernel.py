@@ -1,140 +1,116 @@
 """Pallas TPU kernel: batched range-scan gather over unsorted leaf slots.
 
 The tree's unsorted leaves make a range scan a *mask + compact* problem: the
-leaf frontier for a query ``[lo, hi)`` is gathered by the caller (HBM → VMEM
-rows, exactly the ``leaf_probe`` layout) and flattened to ``n`` candidate
-slots per query; the kernel then
+leaf frontier for a query ``[lo, hi)`` is gathered by the caller and
+flattened to ``n`` candidate slots per query; the kernel then
 
-  1. lane-parallel compares every candidate against the interval (one VPU
-     op per VREG of slots),
+  1. lane-parallel compares every candidate against the interval,
   2. compacts the matches into a fixed-capacity, *ascending* output via
      rank-selection: the rank of a matching key is the number of smaller
-     matching keys, computed as a masked pairwise compare-reduce.  Output
-     lane ``c`` then selects the key with rank ``c`` by masked sum — no
-     scatter, no sort network, all VPU-friendly ops.
+     matching keys, and output row ``c`` selects the key of rank ``c`` by a
+     masked sum — no scatter, no sort network, all VPU-friendly ops.
 
-The pairwise rank is O(n²) per query.  For small frontiers (n = a few
-hundred candidate slots) the full (n, n) compare runs at VREG width and the
-kernel stays memory-bound on the leaf gather; for large frontiers the
-quadratic plane blows past VMEM, so ``tile_n`` blocks the rank into
-(n/T)×(n/T) VREG tiles — per-tile partial ranks accumulate into the same
-integer rank vector (exact: sums of disjoint 0/1 tiles), and the rank-c
-selection walks candidate tiles the same way, so peak live memory drops
-from n² to n·T while staying bit-identical to the pairwise kernel.  Keys
-are int32 on device (TPU has no int64 vector support — the tree's 64-bit
-keys take the pure-jnp ref path; see ops.py).
+Layout: queries run along the 128 lanes and candidates along the
+sublanes, so one grid step serves 128 queries and every value in the
+kernel is a 2-D ``(rows, 128)`` plane (the TPU compiler refuses the 3-D
+``(TB, n, n)`` broadcasts of a query-per-row layout).  The wrapper
+transposes ``(B, n)`` candidates to ``(n, B)`` and the ``(cap, B)`` result
+back.  The rank is a loop over candidate rows ``j``: row ``j`` (one
+query-wide vector) is compared against the candidates being ranked and the
+0/1 result accumulates into an int32 rank plane.
+
+Two variants, bit-identical (integer partial ranks):
+
+  * pairwise — one pass ranks all ``n`` candidates at once (an ``(n, 128)``
+    live plane);
+  * tiled (``tile_n``) — candidates are ranked and selected ``T`` rows at a
+    time, so the live planes are ``(T, 128)`` whatever ``n`` is.
+
+Keys are int32 on device (TPU has no int64 vector support — the tree's
+64-bit keys take the pure-jnp ref path; see ops.py).
 
 Dtype discipline: the host package enables jax_enable_x64, under which
-integer reductions of int32 promote to int64 — every reduction here pins
-``dtype=jnp.int32`` so stores match the int32 output refs.
+integer reductions of int32 promote to int64 and Python-int constants and
+loop bounds trace as int64 — every reduction pins ``dtype=jnp.int32`` and
+every constant and ``fori_loop`` bound is built as ``jnp.int32`` inside the
+kernel body.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels import interpret_mode
 
 INT32_MAX = jnp.iinfo(jnp.int32).max  # EMPTY sentinel for device keys
+LANES = 128  # queries per grid step
 
 
 def _range_scan_kernel(
-    cand_keys_ref, cand_vals_ref, lo_ref, hi_ref,
-    keys_ref, vals_ref, count_ref, trunc_ref,
-    *, cap: int,
+    keys_ref, vals_ref, lo_ref, hi_ref,
+    okeys_ref, ovals_ref, count_ref, trunc_ref,
+    km_ref, rank_ref,
+    *, cap: int, tile: int,
 ):
-    """One (TB, n) tile: interval mask + rank-select compaction."""
-    rows = cand_keys_ref[...]  # (TB, n) int32
-    vals = cand_vals_ref[...]  # (TB, n) int32
-    lo = lo_ref[...]  # (TB, 1)
-    hi = hi_ref[...]  # (TB, 1)
-
-    match = (rows >= lo) & (rows < hi) & (rows != INT32_MAX)  # (TB, n)
-    key_m = jnp.where(match, rows, INT32_MAX)
+    """One block of 128 queries (lanes) × ``n`` candidates (sublanes)."""
+    empty = jnp.int32(INT32_MAX)
+    zero = jnp.int32(0)
+    n = keys_ref.shape[0]
+    rows = keys_ref[...]  # (n, 128)
+    match = (rows >= lo_ref[...]) & (rows < hi_ref[...]) & (rows != empty)
+    # non-matches sit at INT32_MAX: never smaller than a match, never selected
+    km_ref[...] = jnp.where(match, rows, empty)
 
     # rank of each matching key = #matching keys strictly smaller (keys are
-    # unique within a tree, and non-matches sit at INT32_MAX, never smaller).
-    lt = key_m[:, :, None] > key_m[:, None, :]  # (TB, n, n): j smaller than i
-    rank = jnp.sum(lt.astype(jnp.int32), axis=2, dtype=jnp.int32)  # (TB, n)
+    # unique within a tree), accumulated over candidate rows j.
+    for t0 in range(0, n, tile):  # static: one tile (pairwise) or n/T
+        ki = km_ref[t0 : t0 + tile, :]
 
-    # output lane c takes the key of rank c (masked sum — no gather/scatter).
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (rows.shape[0], rows.shape[1], cap), 2)
-    sel = match[:, :, None] & (rank[:, :, None] == c_iota)  # (TB, n, cap)
-    hit = jnp.sum(sel.astype(jnp.int32), axis=1, dtype=jnp.int32) > 0  # (TB, cap)
-    out_k = jnp.sum(jnp.where(sel, rows[:, :, None], jnp.int32(0)), axis=1, dtype=jnp.int32)
-    out_v = jnp.sum(jnp.where(sel, vals[:, :, None], jnp.int32(0)), axis=1, dtype=jnp.int32)
+        def rank_row(j, acc, ki=ki):
+            kj = km_ref[pl.ds(j, 1), :]  # (1, 128)
+            return acc + (kj < ki).astype(jnp.int32)
 
-    total = jnp.sum(match.astype(jnp.int32), axis=1, keepdims=True, dtype=jnp.int32)
-    keys_ref[...] = jnp.where(hit, out_k, jnp.int32(INT32_MAX))
-    vals_ref[...] = jnp.where(hit, out_v, jnp.int32(0))
-    count_ref[...] = jnp.minimum(total, jnp.int32(cap))
-    trunc_ref[...] = (total > cap).astype(jnp.int32)
-
-
-def _range_scan_kernel_tiled(
-    cand_keys_ref, cand_vals_ref, lo_ref, hi_ref,
-    keys_ref, vals_ref, count_ref, trunc_ref,
-    *, cap: int, tile_n: int,
-):
-    """One (TB, n) tile with the rank blocked into (n/T)×(n/T) sub-tiles:
-    bit-identical outputs to ``_range_scan_kernel`` at n·T peak memory."""
-    rows = cand_keys_ref[...]  # (TB, n) int32
-    vals = cand_vals_ref[...]  # (TB, n) int32
-    lo = lo_ref[...]  # (TB, 1)
-    hi = hi_ref[...]  # (TB, 1)
-    tb, n = rows.shape
-    n_tiles = n // tile_n
-
-    match = (rows >= lo) & (rows < hi) & (rows != INT32_MAX)  # (TB, n)
-    key_m = jnp.where(match, rows, INT32_MAX)
-
-    # rank accumulation: tile t contributes #{j ∈ tile : key_m[j] < key_m[i]}
-    # — integer partial sums, so tiling is exact (same rank as pairwise).
-    def rank_tile(t, acc):
-        tile = jax.lax.dynamic_slice_in_dim(key_m, t * tile_n, tile_n, axis=1)
-        gt = key_m[:, :, None] > tile[:, None, :]  # (TB, n, T)
-        return acc + jnp.sum(gt.astype(jnp.int32), axis=2, dtype=jnp.int32)
-
-    rank = jax.lax.fori_loop(0, n_tiles, rank_tile, jnp.zeros((tb, n), jnp.int32))
-
-    # rank-c selection, also walked tile by tile: each output lane sums at
-    # most one candidate across all tiles (ranks of matches are unique).
-    c_iota = jax.lax.broadcasted_iota(jnp.int32, (tb, tile_n, cap), 2)
-
-    def sel_tile(t, carry):
-        hit, out_k, out_v = carry
-        sl = lambda a: jax.lax.dynamic_slice_in_dim(a, t * tile_n, tile_n, axis=1)
-        sel = sl(match)[:, :, None] & (sl(rank)[:, :, None] == c_iota)  # (TB,T,cap)
-        hit = hit + jnp.sum(sel.astype(jnp.int32), axis=1, dtype=jnp.int32)
-        out_k = out_k + jnp.sum(
-            jnp.where(sel, sl(rows)[:, :, None], 0), axis=1, dtype=jnp.int32
+        rank_ref[t0 : t0 + tile, :] = jax.lax.fori_loop(
+            jnp.int32(0), jnp.int32(n), rank_row,
+            jnp.zeros((tile, LANES), jnp.int32),
         )
-        out_v = out_v + jnp.sum(
-            jnp.where(sel, sl(vals)[:, :, None], 0), axis=1, dtype=jnp.int32
-        )
-        return hit, out_k, out_v
 
-    z = jnp.zeros((tb, cap), jnp.int32)
-    hit, out_k, out_v = jax.lax.fori_loop(0, n_tiles, sel_tile, (z, z, z))
-    hit = hit > 0
+    # output row c takes the key of rank c (masked sum over candidates).
+    def select(c, carry):
+        hit = jnp.zeros((1, LANES), jnp.int32)
+        out_k = jnp.zeros((1, LANES), jnp.int32)
+        out_v = jnp.zeros((1, LANES), jnp.int32)
+        for t0 in range(0, n, tile):
+            km = km_ref[t0 : t0 + tile, :]
+            sel = (km != empty) & (rank_ref[t0 : t0 + tile, :] == c)
+            hit += jnp.sum(sel.astype(jnp.int32), axis=0, keepdims=True, dtype=jnp.int32)
+            out_k += jnp.sum(jnp.where(sel, km, zero), axis=0, keepdims=True, dtype=jnp.int32)
+            out_v += jnp.sum(
+                jnp.where(sel, vals_ref[t0 : t0 + tile, :], zero),
+                axis=0, keepdims=True, dtype=jnp.int32,
+            )
+        okeys_ref[pl.ds(c, 1), :] = jnp.where(hit > 0, out_k, empty)
+        ovals_ref[pl.ds(c, 1), :] = jnp.where(hit > 0, out_v, zero)
+        return carry
 
-    total = jnp.sum(match.astype(jnp.int32), axis=1, keepdims=True, dtype=jnp.int32)
-    keys_ref[...] = jnp.where(hit, out_k, jnp.int32(INT32_MAX))
-    vals_ref[...] = jnp.where(hit, out_v, jnp.int32(0))
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(cap), select, jnp.int32(0))
+    total = jnp.sum(match.astype(jnp.int32), axis=0, keepdims=True, dtype=jnp.int32)
     count_ref[...] = jnp.minimum(total, jnp.int32(cap))
-    trunc_ref[...] = (total > cap).astype(jnp.int32)
+    trunc_ref[...] = (total > jnp.int32(cap)).astype(jnp.int32)
 
 
-# Candidate widths past this auto-route to the tiled kernel (the pairwise
-# (n, n) plane at 512² × 4 B ≈ 1 MB/row-block is where VMEM pressure starts).
+# Candidate widths past this auto-route to the tiled variant, whose live
+# planes stay (T, 128) however wide the leaf frontier grows.
 TILE_AUTO_THRESHOLD = 256
 _DEFAULT_TILE = 128
 
 
-@functools.partial(
-    jax.jit, static_argnames=("cap", "block_b", "tile_n", "interpret")
-)
+@functools.partial(jax.jit, static_argnames=("cap", "tile_n", "interpret"))
 def range_scan_pallas(
     cand_keys: jax.Array,  # (B, n) int32 gathered leaf slots, INT32_MAX-padded
     cand_vals: jax.Array,  # (B, n) int32
@@ -142,9 +118,8 @@ def range_scan_pallas(
     hi: jax.Array,  # (B,) int32 exclusive
     *,
     cap: int = 128,
-    block_b: int = 8,
     tile_n: int = 0,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Returns ``(keys (B,cap), vals (B,cap), count (B,), truncated (B,))``
     with keys ascending and INT32_MAX-padded.
@@ -155,54 +130,45 @@ def range_scan_pallas(
     bsz, n = cand_keys.shape
     if tile_n == 0:
         tile_n = _DEFAULT_TILE if n > TILE_AUTO_THRESHOLD else -1
-    if tile_n > 0:
-        pad_n = (-n) % tile_n
-        if pad_n:  # INT32_MAX pad: never matches, never outranks a real key
-            cand_keys = jnp.pad(
-                cand_keys, ((0, 0), (0, pad_n)), constant_values=INT32_MAX
-            )
-            cand_vals = jnp.pad(cand_vals, ((0, 0), (0, pad_n)))
-        n = cand_keys.shape[1]
-        kernel = functools.partial(
-            _range_scan_kernel_tiled, cap=cap, tile_n=tile_n
-        )
-    else:
-        kernel = functools.partial(_range_scan_kernel, cap=cap)
-    pad = (-bsz) % block_b
-    if pad:
-        cand_keys = jnp.pad(cand_keys, ((0, pad), (0, 0)), constant_values=INT32_MAX)
-        cand_vals = jnp.pad(cand_vals, ((0, pad), (0, 0)))
-        lo = jnp.pad(lo, (0, pad))
-        hi = jnp.pad(hi, (0, pad))
-    m = cand_keys.shape[0]
-    grid = (m // block_b,)
+    # candidate rows pad to the sublane tile (8) and to the tile width; the
+    # INT32_MAX pad never matches and never outranks a real key.
+    step = 8 if tile_n <= 0 else max(8, tile_n + (-tile_n) % 8)
+    n_pad = n + (-n) % step
+    tile = n_pad if tile_n <= 0 else step
+    b_pad = bsz + (-bsz) % LANES
+    keys_t = jnp.pad(
+        cand_keys.astype(jnp.int32), ((0, b_pad - bsz), (0, n_pad - n)),
+        constant_values=INT32_MAX,
+    ).T
+    vals_t = jnp.pad(
+        cand_vals.astype(jnp.int32), ((0, b_pad - bsz), (0, n_pad - n))
+    ).T
+    lo_t = jnp.pad(lo.astype(jnp.int32), (0, b_pad - bsz))[None, :]
+    hi_t = jnp.pad(hi.astype(jnp.int32), (0, b_pad - bsz))[None, :]
+    # index maps return int32 block indices (a bare 0 traces as int64 under
+    # x64, which the TPU compiler refuses)
+    col = lambda rows: pl.BlockSpec((rows, LANES), lambda i: (jnp.int32(0), i))
     out_shape = [
-        jax.ShapeDtypeStruct((m, cap), jnp.int32),  # keys
-        jax.ShapeDtypeStruct((m, cap), jnp.int32),  # vals
-        jax.ShapeDtypeStruct((m, 1), jnp.int32),  # count
-        jax.ShapeDtypeStruct((m, 1), jnp.int32),  # truncated
+        jax.ShapeDtypeStruct((cap, b_pad), jnp.int32),  # keys
+        jax.ShapeDtypeStruct((cap, b_pad), jnp.int32),  # vals
+        jax.ShapeDtypeStruct((1, b_pad), jnp.int32),  # count
+        jax.ShapeDtypeStruct((1, b_pad), jnp.int32),  # truncated
     ]
     keys, vals, count, trunc = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, n), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_b, cap), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, cap), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i: (i, 0)),
-        ],
+        functools.partial(_range_scan_kernel, cap=cap, tile=tile),
+        grid=(b_pad // LANES,),
+        in_specs=[col(n_pad), col(n_pad), col(1), col(1)],
+        out_specs=[col(cap), col(cap), col(1), col(1)],
         out_shape=out_shape,
-        interpret=interpret,
-    )(cand_keys, cand_vals, lo[:, None].astype(jnp.int32), hi[:, None].astype(jnp.int32))
+        scratch_shapes=[
+            pltpu.VMEM((n_pad, LANES), jnp.int32),  # masked keys
+            pltpu.VMEM((n_pad, LANES), jnp.int32),  # ranks
+        ],
+        interpret=interpret_mode(interpret),
+    )(keys_t, vals_t, lo_t, hi_t)
     return (
-        keys[:bsz],
-        vals[:bsz],
-        count[:bsz, 0],
-        trunc[:bsz, 0].astype(bool),
+        keys.T[:bsz],
+        vals.T[:bsz],
+        count[0, :bsz],
+        trunc[0, :bsz].astype(bool),
     )

@@ -21,6 +21,8 @@ key.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -37,7 +39,7 @@ def range_scan(
     cap: int = 128,
     use_pallas: bool = True,
     narrow: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fixed-capacity ascending gather of candidate keys in [lo, hi).
 

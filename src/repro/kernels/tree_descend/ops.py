@@ -19,6 +19,8 @@ test oracle.
 """
 from __future__ import annotations
 
+from typing import Optional
+
 import jax
 import jax.numpy as jnp
 
@@ -33,10 +35,20 @@ from repro.kernels.tree_descend.ref import (
     probe_ref,
 )
 
-# Pool planes past this many rows exceed the per-core VMEM budget for the
-# resident-pool layout (keys+vals+children ≈ 3·rows·b·4 B); larger pools
-# take the ref path even under the narrow gate.
-MAX_POOL_ROWS = 1 << 17
+# The largest pool (rows, scratch row included) whose packed record plane
+# the v5e compiler accepts in VMEM: a 2**19-node pool packs into 64 MiB and
+# compiles; 2**20 needs 128 MiB plus scratch and is refused ("Ran out of
+# memory in memory space vmem").  Found by compiling for a described v5e
+# and pinned by tests/test_chip_compile.py.  Larger pools take the ref path
+# even under the narrow gate.
+MAX_POOL_ROWS = (1 << 19) + 1
+
+
+def descend_probe_uses_kernel(n_rows: int, key_dtype, narrow: bool) -> bool:
+    """The dispatch gate of :func:`descend_probe`: the Pallas kernel runs
+    iff the keys are int32 (or the caller's narrow gate asserts they fit)
+    and the pool fits VMEM (``n_rows <= MAX_POOL_ROWS``)."""
+    return (narrow or key_dtype == jnp.int32) and n_rows <= MAX_POOL_ROWS
 
 
 def descend_probe(
@@ -51,7 +63,7 @@ def descend_probe(
     notfound,
     narrow: bool = False,
     use_pallas: bool = True,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Fused search phase: root-to-leaf descent + unsorted-leaf probe.
 
@@ -59,8 +71,9 @@ def descend_probe(
     val (B,))`` with ``val == notfound`` where absent — exactly the
     ``descend_ref``/``probe_ref`` composition on every path.
     """
-    eligible = narrow or pool_keys.dtype == jnp.int32
-    if use_pallas and eligible and pool_keys.shape[0] <= MAX_POOL_ROWS:
+    if use_pallas and descend_probe_uses_kernel(
+        pool_keys.shape[0], pool_keys.dtype, narrow
+    ):
         empty = jnp.iinfo(pool_keys.dtype).max
         pk = jnp.where(pool_keys == empty, INT32_MAX, pool_keys).astype(jnp.int32)
         q = jnp.where(queries == empty, INT32_MAX, queries).astype(jnp.int32)
@@ -89,7 +102,7 @@ def frontier_compact(
     *,
     scratch: int,
     use_pallas: bool = False,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """Stable, sort-free compaction of each row's valid candidates into a
     width-``f`` frontier.  Returns ``(frontier (B, f) int32, valid (B, f)
@@ -126,4 +139,5 @@ __all__ = [
     "frontier_compact",
     "frontier_compact_pallas",
     "MAX_POOL_ROWS",
+    "descend_probe_uses_kernel",
 ]

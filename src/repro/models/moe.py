@@ -50,7 +50,7 @@ def _dispatch_ffn(p, xf, cfg, cap: int):
 
     # flatten (token, slot) pairs and rank within expert by sorted order
     eid = topi.reshape(-1)  # (T*k,)
-    tok = jnp.repeat(jnp.arange(t), k)
+    tok = jnp.arange(t * k) // k  # token of each (token, slot) pair
     w = topw.reshape(-1)
     order = jnp.argsort(eid, stable=True)
     eid_s, tok_s, w_s = eid[order], tok[order], w[order]
@@ -100,16 +100,15 @@ def _grouped_dispatch(p, xg, cfg, cap: int):
         return run(p, xg)
     from jax.sharding import PartitionSpec as PS
 
-    from repro._shardmap_compat import shard_map_compat
-
     # shard_map with the manual axes; the model axis stays auto so the
     # partitioner still applies TP/EP weight sharding inside.
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         run,
         mesh=mesh,
         in_specs=(jax.tree.map(lambda _: PS(), p), PS(manual, None, None)),
         out_specs=PS(manual, None, None),
-        manual=manual,
+        axis_names=set(manual),
+        check_vma=False,
     )
     return fn(p, xg)
 

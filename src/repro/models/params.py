@@ -67,17 +67,17 @@ def logical_to_pspec(axes, rules) -> PartitionSpec:
     return PartitionSpec(*phys)
 
 
-def _leaf_key(path: str) -> jax.Array:
+def _leaf_key(path: str, seed: int) -> jax.Array:
     h = int.from_bytes(hashlib.sha256(path.encode()).digest()[:4], "little")
-    return jax.random.key(h)
+    return jax.random.fold_in(jax.random.key(h), seed)
 
 
-def init_leaf(spec: P, path: str) -> jax.Array:
+def init_leaf(spec: P, path: str, seed: int = 0) -> jax.Array:
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, spec.dtype)
     if spec.init == "ones":
         return jnp.ones(spec.shape, spec.dtype)
-    k = _leaf_key(path)
+    k = _leaf_key(path, seed)
     scale = spec.scale
     if spec.init == "embed":
         scale = 1.0 / np.sqrt(spec.shape[-1])
@@ -107,8 +107,10 @@ def map_specs(fn, tree):
     return rec(tree, "")
 
 
-def init_params(spec_tree) -> dict:
-    return map_specs(lambda p, s: init_leaf(s, p), spec_tree)
+def init_params(spec_tree, seed: int = 0) -> dict:
+    """Random parameters: each leaf's key folds ``seed`` into a hash of its
+    path, so one seed fixes the whole tree."""
+    return map_specs(lambda p, s: init_leaf(s, p, seed), spec_tree)
 
 
 def abstract_params(spec_tree) -> dict:

@@ -79,13 +79,12 @@ def pipeline_apply(
         jax.tree.map(lambda _: PS(axis), stage_params),
         PS(),  # microbatches replicated in (activations stream through)
     )
-    from repro._shardmap_compat import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         shard_fn,
         mesh=mesh,
         in_specs=in_specs,
         out_specs=PS(),
-        manual=manual,
+        axis_names=manual,
+        check_vma=False,
     )
     return fn(stage_params, x)

@@ -83,7 +83,7 @@ class ServeEngine:
         # inline.  run_until_done() drains pending groups at exit.
         if commit_async is None:
             commit_async = group_commit_every > 1
-        self.params = init_params(backbone.model_spec(cfg))
+        self.params = init_params(backbone.model_spec(cfg), seed=seed)
         self.kv = PagedKVCache(n_pages)
         # index_shards > 1 partitions both indexes' key spaces into an
         # ABForest (one vmapped round per scheduler tick, per index).
@@ -144,9 +144,27 @@ class ServeEngine:
         self._decode = jax.jit(
             lambda p, c, t, q: backbone.forward_decode(p, c, t, q, cfg)
         )
-        self._prefill_tok = jax.jit(
-            lambda p, c, t, q: backbone.forward_decode(p, c, t, q, cfg)
+        # the batch axis of every cache leaf (the layout differs by family)
+        one, two = (backbone.cache_spec(cfg, n, s_max) for n in (1, 2))
+        batch_axes = jax.tree.map(
+            lambda a, b: [x != y for x, y in zip(a.shape, b.shape)].index(True),
+            one, two,
         )
+
+        def prefill_step(p, c, t, q, slot):
+            """One prompt token of one slot.  The decode step writes every
+            slot's cache row at ``q``; keep the other slots' rows, or a
+            prefill clobbers the prompts of requests admitted before it."""
+            logits, new = backbone.forward_decode(p, c, t, q, cfg)
+
+            def keep_others(n, o, ax):
+                mine = jnp.arange(n.shape[ax]) == slot
+                shape = [-1 if i == ax else 1 for i in range(n.ndim)]
+                return jnp.where(mine.reshape(shape), n, o)
+
+            return logits, jax.tree.map(keep_others, new, c, batch_axes)
+
+        self._prefill_tok = jax.jit(prefill_step)
 
     # ------------------------------------------------------------------ --
 
@@ -218,8 +236,9 @@ class ServeEngine:
     def _step_slot(self, slot: int, tok: int):
         tokens = np.zeros(self.max_batch, np.int32)
         tokens[slot] = tok
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(tokens), jnp.int32(int(self.pos[slot]))
+        logits, self.cache = self._prefill_tok(
+            self.params, self.cache, jnp.asarray(tokens),
+            jnp.int32(int(self.pos[slot])), jnp.int32(slot),
         )
         self.pos[slot] += 1
         return logits

@@ -48,6 +48,24 @@ def test_pipelined_completes_same_requests_as_serial():
     assert results[True]["lens"] == [4] * 6
 
 
+def test_prefill_keeps_other_slots_cache():
+    """A slot's prefill writes only its own cache row: the cache a request
+    built during its prefill is the same whether or not another request
+    was admitted (and prefilled) after it in the same tick.  The decode
+    step writes every row, so an unmasked prefill would overwrite the
+    earlier request's prompt with the later one's padding tokens."""
+    import jax
+
+    rows = []
+    for n in (1, 2):
+        eng = _mk_engine(max_batch=2)
+        _submit_all(eng, n, seed=4)
+        eng._admit()
+        rows.append([np.asarray(x)[:, 0, :, :7] for x in jax.tree.leaves(eng.cache)])
+    for alone, shared in zip(*rows):
+        np.testing.assert_array_equal(alone, shared)
+
+
 def test_tick_overlap_frac_is_positive():
     """The whole point of the double-buffered tick: admit work runs WHILE a
     decode is in flight, so the overlap fraction must be strictly positive
