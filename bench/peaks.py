@@ -1,0 +1,26 @@
+"""Published peaks of each accelerator the benchmark accepts, keyed by
+JAX's ``device_kind``.  Source: Google Cloud documentation, "TPU v5e"
+(cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 393 TOP/s int8,
+16 GB of HBM at 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect.
+A device that is not in the table is an error, never a default."""
+from __future__ import annotations
+
+PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops_per_s": 197e12,
+        "int8_ops_per_s": 393e12,
+        "hbm_bytes_per_s": 819e9,
+        "hbm_bytes": 16e9,
+        "ici_bits_per_s": 1600e9,
+        "source": "Google Cloud documentation, TPU v5e",
+    },
+}
+
+
+def peaks(device_kind: str) -> dict:
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"the table has {sorted(PEAKS)}"
+        )
+    return PEAKS[device_kind]
