@@ -61,7 +61,7 @@ from repro.obs.metrics import (
     engine_collector,
 )
 from repro.obs.recorder import Recorder
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import NULL_TRACER, pull
 
 # ----------------------------------------------------------------------------
 # Constants & state
@@ -1016,7 +1016,7 @@ class ABTree(RegistryBackedCounters):
         (negative span, i.e. hi < lo) raise ``ValueError``."""
         from repro.core import rounds
 
-        plan = rounds.build_plan(ops, keys, vals, scan_cap=scan_cap)
+        plan = rounds.build_plan(ops, keys, vals, scan_cap=scan_cap, holder=self)
         return rounds.execute_plan(self, plan)
 
     def scan_round(self, lo, hi, cap: int = 128, max_retries: int = 8) -> ScanOutput:
@@ -1059,24 +1059,26 @@ class ABTree(RegistryBackedCounters):
 
         return rounds.execute_scan_stream(self, lo, hi, cap)
 
+    def _one(self, out) -> Optional[int]:
+        """A one-lane round's answer (None where the lane found nothing)."""
+        found = pull(self, "answers", out.found)[0]
+        return int(pull(self, "answers", out.results)[0]) if found else None
+
     def find(self, key) -> Optional[int]:
-        out = self.apply_round([OP_FIND], [key])
-        return int(out.results[0]) if bool(out.found[0]) else None
+        return self._one(self.apply_round([OP_FIND], [key]))
 
     def insert(self, key, val):
-        out = self.apply_round([OP_INSERT], [key], [val])
-        return int(out.results[0]) if bool(out.found[0]) else None
+        return self._one(self.apply_round([OP_INSERT], [key], [val]))
 
     def delete(self, key):
-        out = self.apply_round([OP_DELETE], [key])
-        return int(out.results[0]) if bool(out.found[0]) else None
+        return self._one(self.apply_round([OP_DELETE], [key]))
 
     def items(self) -> dict:
         """Host-side snapshot of the dictionary contents (sorted by key)."""
         s = self.state
-        leaf = np.asarray(s.is_leaf) & np.asarray(s.alloc)
-        keys = np.asarray(s.keys)[leaf].ravel()
-        vals = np.asarray(s.vals)[leaf].ravel()
+        leaf = pull(self, "items", s.is_leaf) & pull(self, "items", s.alloc)
+        keys = pull(self, "items", s.keys)[leaf].ravel()
+        vals = pull(self, "items", s.vals)[leaf].ravel()
         live = keys != int(EMPTY)
         keys, vals = keys[live], vals[live]
         order = np.argsort(keys, kind="stable")
@@ -1084,7 +1086,7 @@ class ABTree(RegistryBackedCounters):
 
     def take_dirty(self) -> np.ndarray:
         """Node ids dirtied since the last durable commit (then reset)."""
-        d = np.nonzero(np.asarray(self.state.dirty))[0].astype(np.int32)
+        d = np.nonzero(pull(self, "commit.dirty", self.state.dirty))[0].astype(np.int32)
         self.state = self.state._replace(dirty=jnp.zeros_like(self.state.dirty))
         return d
 
@@ -1092,8 +1094,16 @@ class ABTree(RegistryBackedCounters):
         """Device phase counters plus the engine's host-side round/scan
         counters (``rounds`` / ``scans`` / ``scan_retries`` are sequenced on
         the host by the unified engine; ``scan_retries`` counts retried
-        *lanes* — ops re-gathered after a version conflict)."""
-        s = {k: int(np.asarray(v).sum()) for k, v in self.state.stats._asdict().items()}
+        *lanes* — ops re-gathered after a version conflict).  ``host_syncs``
+        and ``d2h_bytes`` count every device→host pull so far, read before
+        this call's own pull of the device counters."""
+        syncs, d2h = self.metrics.value("host_syncs"), self.metrics.value("d2h_bytes")
+        s = {
+            k: int(pull(self, "stats", v).sum())
+            for k, v in self.state.stats._asdict().items()
+        }
+        s["host_syncs"] = syncs
+        s["d2h_bytes"] = d2h
         s["rounds"] = self._rounds
         s["scans"] = self._scans
         s["scan_retries"] = self._scan_retries
@@ -1109,7 +1119,7 @@ class ABTree(RegistryBackedCounters):
         need = 2 * need_nodes + 4 * self.cfg.max_height + 2 * self._wave_w + 8
         # the stacked form is the one the engine keeps current; reading
         # ``state`` would slice an unstacked copy of every pool array
-        n_alloc = int(jnp.sum(self.stacked.alloc))
+        n_alloc = int(pull(self, "capacity", jnp.sum(self.stacked.alloc)))
         cap = self.cfg.capacity
         if cap - n_alloc >= need:
             return
@@ -1136,13 +1146,11 @@ def range_query(tree: "ABTree", lo: int, hi: int, max_retries: int = 8):
     cfg = tree.cfg
     for _ in range(max_retries):
         s = tree.state
-        ver_before = np.asarray(s.ver)
-        keys = np.asarray(s.keys)
-        vals = np.asarray(s.vals)
-        children = np.asarray(s.children)
-        is_leaf = np.asarray(s.is_leaf)
-        size = np.asarray(s.size)
-        root = int(s.root)
+        ver_before, keys, vals, children, is_leaf, size, root = (
+            pull(tree, "range_query", x)
+            for x in (s.ver, s.keys, s.vals, s.children, s.is_leaf, s.size, s.root)
+        )
+        root = int(root)
         out = []
         touched = []
         stack = [root]
@@ -1162,7 +1170,7 @@ def range_query(tree: "ABTree", lo: int, hi: int, max_retries: int = 8):
                 chi = int(EMPTY) if j == sz - 1 else int(routers[j])
                 if chi > lo and clo < hi:  # child range intersects [lo, hi)
                     stack.append(int(children[nid, j]))
-        ver_after = np.asarray(tree.state.ver)
+        ver_after = pull(tree, "range_query", tree.state.ver)
         if all(ver_before[t] == ver_after[t] for t in touched):
             return sorted(out)
     raise ScanConflictError("range_query: version validation failed repeatedly")

@@ -150,6 +150,7 @@ from repro.core.faults import (  # noqa: F401  (re-exported for back-compat)
     as_fault_plan,
 )
 from repro.core.forest import ABForest, _stack_states
+from repro.obs.tracer import pull
 
 _PERSISTED_FIELDS = ("keys", "vals", "children", "is_leaf", "level")
 # NOT persisted (volatile; rebuilt by recovery, as in the paper §5 — only
@@ -533,29 +534,31 @@ class _DurableBase:
         # -- synchronous capture: everything the commit reads from the
         # LIVE holder (which keeps mutating under async commits) is pinned
         # here; jnp arrays are immutable, so the host views stay valid.
-        cap = {
-            "idx": self._commit_idx,
-            "force_snapshot": force_snapshot,
-            "dirty": self._take_dirty_all(),
-            "shard_arrays": self._persisted_host_arrays(),
-            "roots": [
-                self._shard_root_height(s) for s in range(self._n_shards())
-            ],
-            "capacity": self._capacity(),
-            "mode": self._mode(),
-            "extra": self._manifest_extra(),
-            "absorbed": absorbed,
-            "max_attempts": max_attempts,
-            "was_degraded": self._degraded,
-            "t_start": time.perf_counter(),
-            "sidecar": None,
-        }
-        rec = getattr(self._holder(), "recorder", None)
-        if rec is not None and rec.enabled:
-            # the sidecar must describe the COMMITTED prefix, not whatever
-            # rounds run while an async commit is in flight — capture the
-            # ring now, at the group boundary.
-            cap["sidecar"] = (int(self._holder()._rounds), rec.dump_records())
+        # Its own span, beside (never inside) journal_flush/manifest_commit.
+        with self.tracer.span("commit_capture", commit=self._commit_idx):
+            cap = {
+                "idx": self._commit_idx,
+                "force_snapshot": force_snapshot,
+                "dirty": self._take_dirty_all(),
+                "shard_arrays": self._persisted_host_arrays(),
+                "roots": [
+                    self._shard_root_height(s) for s in range(self._n_shards())
+                ],
+                "capacity": self._capacity(),
+                "mode": self._mode(),
+                "extra": self._manifest_extra(),
+                "absorbed": absorbed,
+                "max_attempts": max_attempts,
+                "was_degraded": self._degraded,
+                "t_start": time.perf_counter(),
+                "sidecar": None,
+            }
+            rec = getattr(self._holder(), "recorder", None)
+            if rec is not None and rec.enabled:
+                # the sidecar must describe the COMMITTED prefix, not
+                # whatever rounds run while an async commit is in flight —
+                # capture the ring now, at the group boundary.
+                cap["sidecar"] = (int(self._holder()._rounds), rec.dump_records())
         if self.commit_async and not self._degraded:
             if self._commit_pool is None:
                 self._commit_pool = ThreadPoolExecutor(
@@ -1089,10 +1092,11 @@ class DurableABTree(_DurableBase):
 
     def _persisted_host_arrays(self):
         st = self.tree.state
-        return [{f: np.asarray(getattr(st, f)) for f in _PERSISTED_FIELDS}]
+        return [{f: pull(self, "commit.capture", getattr(st, f)) for f in _PERSISTED_FIELDS}]
 
     def _shard_root_height(self, s: int):
-        return int(self.tree.state.root), int(self.tree.state.height)
+        st = self.tree.state
+        return int(pull(self, "commit.roots", st.root)), int(pull(self, "commit.roots", st.height))
 
     def _capacity(self) -> int:
         return self.tree.cfg.capacity
@@ -1241,7 +1245,7 @@ class DurableForest(_DurableBase):
 
     def _persisted_host_arrays(self):
         st = self.forest.state
-        stacked = {f: np.asarray(getattr(st, f)) for f in _PERSISTED_FIELDS}
+        stacked = {f: pull(self, "commit.capture", getattr(st, f)) for f in _PERSISTED_FIELDS}
         return [
             {f: a[s] for f, a in stacked.items()}
             for s in range(self.forest.n_shards)
@@ -1249,7 +1253,8 @@ class DurableForest(_DurableBase):
 
     def _shard_root_height(self, s: int):
         st = self.forest.state
-        return int(np.asarray(st.root)[s]), int(np.asarray(st.height)[s])
+        return (int(pull(self, "commit.roots", st.root)[s]),
+                int(pull(self, "commit.roots", st.height)[s]))
 
     def _capacity(self) -> int:
         return self.forest.cfg.capacity

@@ -103,12 +103,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import elimination as elim
 from repro.core import rounds
 from repro.core.abtree import (
     EMPTY,
     KEY_MIN,
     OP_DELETE,
+    OP_FIND,
     OP_INSERT,
     RoundOutput,
     ScanOutput,
@@ -124,7 +124,7 @@ from repro.obs.metrics import (
     engine_collector,
 )
 from repro.obs.recorder import Recorder
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import NULL_TRACER, pull
 
 
 def _stack_states(states: List[TreeState]) -> TreeState:
@@ -355,7 +355,7 @@ class ABForest(RegistryBackedCounters):
         round, and per-lane results come back batch-aligned.  Cross-shard
         range lanes are split into per-shard sub-lanes and their rows
         stitched back in key order."""
-        plan = rounds.build_plan(ops, keys, vals, scan_cap=scan_cap)
+        plan = rounds.build_plan(ops, keys, vals, scan_cap=scan_cap, holder=self)
         return rounds.execute_plan(self, plan)
 
     def scan_round(self, lo, hi, cap: int = 128, max_retries: int = 8) -> ScanOutput:
@@ -381,24 +381,26 @@ class ABForest(RegistryBackedCounters):
         ``cap`` entries (and one shard's gather) per round."""
         return rounds.execute_scan_stream(self, lo, hi, cap)
 
+    def _one(self, out) -> Optional[int]:
+        """A one-lane round's answer (None where the lane found nothing)."""
+        found = pull(self, "answers", out.found)[0]
+        return int(pull(self, "answers", out.results)[0]) if found else None
+
     def find(self, key) -> Optional[int]:
-        out = self.apply_round([elim.OP_FIND], [key])
-        return int(out.results[0]) if bool(out.found[0]) else None
+        return self._one(self.apply_round([OP_FIND], [key]))
 
     def insert(self, key, val):
-        out = self.apply_round([OP_INSERT], [key], [val])
-        return int(out.results[0]) if bool(out.found[0]) else None
+        return self._one(self.apply_round([OP_INSERT], [key], [val]))
 
     def delete(self, key):
-        out = self.apply_round([OP_DELETE], [key])
-        return int(out.results[0]) if bool(out.found[0]) else None
+        return self._one(self.apply_round([OP_DELETE], [key]))
 
     def items(self) -> dict:
         """Host-side snapshot of the forest contents (sorted by key)."""
         st = self.state
-        keys = np.asarray(st.keys)
-        vals = np.asarray(st.vals)
-        leaf = np.asarray(st.is_leaf) & np.asarray(st.alloc)
+        keys = pull(self, "items", st.keys)
+        vals = pull(self, "items", st.vals)
+        leaf = pull(self, "items", st.is_leaf) & pull(self, "items", st.alloc)
         out = {}
         for s in range(self.n_shards):
             for nid in np.nonzero(leaf[s])[0]:
@@ -418,7 +420,7 @@ class ABForest(RegistryBackedCounters):
         """Per-shard node ids dirtied since the last durable commit (then
         reset) — each shard's journal segment is exactly one of these
         lists, so an untouched shard flushes nothing."""
-        d = np.asarray(self.state.dirty)
+        d = pull(self, "commit.dirty", self.state.dirty)
         self.state = self.state._replace(dirty=jnp.zeros_like(self.state.dirty))
         return [
             np.nonzero(d[s])[0].astype(np.int32) for s in range(self.n_shards)
@@ -428,19 +430,24 @@ class ABForest(RegistryBackedCounters):
         """Forest-level stats: device counters summed over shards;
         ``rounds`` counts forest rounds (one vmapped round = 1, however many
         shards it spans) and ``scan_retries`` counts retried *lanes* — the
-        per-op conflict cost per-shard validation buys down."""
+        per-op conflict cost per-shard validation buys down.  ``host_syncs``
+        and ``d2h_bytes`` are read before this call's own pull."""
+        syncs, d2h = self.metrics.value("host_syncs"), self.metrics.value("d2h_bytes")
         agg = {
-            k: int(np.asarray(v).sum())
+            k: int(pull(self, "stats", v).sum())
             for k, v in self.state.stats._asdict().items()
         }
+        agg["host_syncs"] = syncs
+        agg["d2h_bytes"] = d2h
         agg["rounds"] = self._rounds
         agg["scans"] = self._scans
         agg["scan_retries"] = self._scan_retries
         return agg
 
     def stats_per_shard(self) -> List[dict]:
+        stats = {k: pull(self, "stats", v) for k, v in self.state.stats._asdict().items()}
         return [
-            {k: int(np.asarray(v)[s]) for k, v in self.state.stats._asdict().items()}
+            {k: int(a[s]) for k, a in stats.items()}
             for s in range(self.n_shards)
         ]
 
@@ -452,8 +459,8 @@ class ABForest(RegistryBackedCounters):
 
     def _live_key_counts(self) -> np.ndarray:
         st = self.state
-        leaf = np.asarray(st.is_leaf) & np.asarray(st.alloc)
-        return np.sum(np.where(leaf, np.asarray(st.size), 0), axis=1)
+        leaf = pull(self, "shard_split", st.is_leaf) & pull(self, "shard_split", st.alloc)
+        return np.sum(np.where(leaf, pull(self, "shard_split", st.size), 0), axis=1)
 
     def _maybe_split_shards(self):
         self._maybe_repartition()
@@ -485,10 +492,10 @@ class ABForest(RegistryBackedCounters):
         try:
             while True:
                 out = self.scan_delete_round([lo], [hi], cap=cap)
-                n = int(np.asarray(out.count)[0])
-                moved_k.extend(int(k) for k in np.asarray(out.keys)[0, :n])
-                moved_v.extend(int(v) for v in np.asarray(out.vals)[0, :n])
-                if not bool(np.asarray(out.truncated)[0]):
+                n = int(pull(self, "shard_split", out.count)[0])
+                moved_k.extend(int(k) for k in pull(self, "shard_split", out.keys)[0, :n])
+                moved_v.extend(int(v) for v in pull(self, "shard_split", out.vals)[0, :n])
+                if not bool(pull(self, "shard_split", out.truncated)[0]):
                     break
         finally:
             self._scan_frontier = frontier0
@@ -511,8 +518,8 @@ class ABForest(RegistryBackedCounters):
         self._in_split = True
         try:
             st = self.state
-            leaf = np.asarray(st.is_leaf)[s] & np.asarray(st.alloc)[s]
-            krows = np.asarray(st.keys)[s][leaf]
+            leaf = pull(self, "shard_split", st.is_leaf)[s] & pull(self, "shard_split", st.alloc)[s]
+            krows = pull(self, "shard_split", st.keys)[s][leaf]
             ks = krows[krows != int(EMPTY)]
             if ks.size < 2:
                 return
@@ -681,7 +688,7 @@ class ABForest(RegistryBackedCounters):
         The 2·wave_w term keeps each pool large enough for a full-width
         split wave's allocation (see ``ABTree._ensure_capacity``)."""
         need = 2 * need_nodes + 4 * self.cfg.max_height + 2 * self._wave_w + 8
-        n_alloc = int(jnp.max(jnp.sum(self.state.alloc, axis=1)))
+        n_alloc = int(pull(self, "capacity", jnp.max(jnp.sum(self.state.alloc, axis=1))))
         cap = self.cfg.capacity
         if cap - n_alloc >= need:
             return

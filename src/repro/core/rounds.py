@@ -73,8 +73,10 @@ Holder protocol (duck-typed; ``ABTree`` and ``ABForest`` both provide it):
   ``_scan_active``          in-flight-scan counter (defers shard splits)
   ``_maybe_split_shards()`` shard-overflow policy (no-op on ABTree)
   ``metrics`` / ``tracer``  telemetry (``repro.obs``): the registry backs
-                            the legacy counters; the tracer wraps phase
-                            launches host-side (NULL_TRACER = no-op)
+                            the legacy counters and counts every
+                            device→host pull (``repro.obs.pull``); the
+                            tracer wraps phase launches host-side
+                            (NULL_TRACER = no-op)
   ``recorder``              flight recorder (``repro.obs.recorder``): one
                             semantic audit record per round, captured
                             host-side at round boundaries (NULL_RECORDER
@@ -98,6 +100,8 @@ from repro.core.abtree import (
     KEY_DTYPE,
     NOTFOUND,
     OP_DELETE,
+    OP_FIND,
+    OP_INSERT,
     OP_NOP,
     OP_RANGE,
     RoundOutput,
@@ -116,7 +120,7 @@ from repro.core.abtree import (
 from repro.kernels.range_scan.ops import range_scan
 from repro.kernels.tree_descend.ops import descend_probe
 from repro.obs.recorder import NULL_RECORDER
-from repro.obs.tracer import NULL_TRACER
+from repro.obs.tracer import NULL_TRACER, pull
 
 # ----------------------------------------------------------------------------
 # telemetry accessors (host-side only — spans/counters wrap the jitted
@@ -144,23 +148,23 @@ def _rec(holder):
     return NULL_RECORDER if r is None else r
 
 
-def _elim_note(ops_sw, ks, arrival, res) -> dict:
+def _elim_note(holder, ops_sw, ks, arrival, res) -> dict:
     """Host summary of one combine's elimination decisions: per-shard
     eliminated-op counts plus every multi-update key segment (the
     annihilated insert/delete pairings) with its net physical action.
     Built only when a recorder is enabled."""
-    ks_np = np.asarray(ks)  # (S, W) key-sorted; EMPTY on NOP lanes
-    arr_np = np.asarray(arrival)  # sorted pos -> packed lane slot
-    seg_np = np.asarray(res.seg_head)
-    ni = np.asarray(res.net_insert)
-    nd = np.asarray(res.net_delete)
-    no = np.asarray(res.net_overwrite)
-    nel = np.asarray(res.n_eliminated).reshape(-1)
-    ops_np = np.asarray(ops_sw)
+    ks_np = pull(holder, "recorder", ks)  # (S, W) key-sorted; EMPTY on NOP lanes
+    arr_np = pull(holder, "recorder", arrival)  # sorted pos -> packed lane slot
+    seg_np = pull(holder, "recorder", res.seg_head)
+    ni = pull(holder, "recorder", res.net_insert)
+    nd = pull(holder, "recorder", res.net_delete)
+    no = pull(holder, "recorder", res.net_overwrite)
+    nel = pull(holder, "recorder", res.n_eliminated).reshape(-1)
+    ops_np = pull(holder, "recorder", ops_sw)
     segments = []
     for s in range(ks_np.shape[0]):
         ops_sorted = ops_np[s][arr_np[s]]
-        upd = (ops_sorted == int(elim.OP_INSERT)) | (ops_sorted == int(elim.OP_DELETE))
+        upd = (ops_sorted == OP_INSERT) | (ops_sorted == OP_DELETE)
         if int(upd.sum()) < 2:
             continue
         seg_id = np.cumsum(seg_np[s]) - 1
@@ -245,18 +249,21 @@ class RoundPlan(NamedTuple):
     scan_cap: int
 
 
-def build_plan(ops, keys, vals=None, *, scan_cap: int = 128) -> RoundPlan:
+def build_plan(ops, keys, vals=None, *, holder, scan_cap: int = 128) -> RoundPlan:
     """Classify one round's lanes and derive the range lanes' intervals.
 
     OP_RANGE lane encoding: ``key = lo``, ``val = span`` → the lane scans
     ``[lo, lo + span)`` (``span == 0`` is a legal empty scan).  Raises
     ``ValueError`` for malformed range lanes (``span < 0``, i.e. hi < lo)
-    and for unknown op codes.
+    and for unknown op codes.  Device-array inputs are pulled through
+    ``repro.obs.pull``, counted in ``holder``'s registry (``None``: no
+    registry counts them).
     """
-    ops_np = np.asarray(ops, np.int32)
-    keys_np = np.asarray(keys, np.int64)
+    ops_np = np.asarray(pull(holder, "plan", ops), np.int32)
+    keys_np = np.asarray(pull(holder, "plan", keys), np.int64)
     vals_np = (
-        np.zeros_like(keys_np) if vals is None else np.asarray(vals, np.int64)
+        np.zeros_like(keys_np) if vals is None
+        else np.asarray(pull(holder, "plan", vals), np.int64)
     )
     if not (ops_np.shape == keys_np.shape == vals_np.shape and ops_np.ndim == 1):
         raise ValueError("apply_round expects equal-length 1-D ops/keys/vals")
@@ -557,7 +564,7 @@ def gather_until_frontier_fits(holder, gather):
     guard = 0
     while True:
         out, touched, overflow = gather(holder._scan_frontier)
-        if not bool(jnp.any(overflow)):
+        if not bool(pull(holder, "scan.frontier", jnp.any(overflow))):
             return out, touched
         guard += 1
         assert guard < 32, "scan frontier growth diverged"
@@ -761,9 +768,10 @@ def run_scan_phase(
                 if holder.scan_hook is not None:
                     holder.scan_hook()
                 with tr.span("scan.validate", attempt=_attempt):
-                    snap_ver = np.asarray(snap.ver)
-                    live_ver = np.asarray(holder.stacked.ver)
-                    touched_np = np.asarray(touched)  # (L, w, F) per-lane ids
+                    snap_ver = pull(holder, "scan.validate", snap.ver)
+                    live_ver = pull(holder, "scan.validate", holder.stacked.ver)
+                    # (L, w, F) per-lane ids
+                    touched_np = pull(holder, "scan.validate", touched)
                     shard_ok = np.zeros(n_s, bool)
                     for s in np.nonzero(pending)[0]:
                         ids = np.unique(touched_np[:, sid_w == s, :])
@@ -791,10 +799,10 @@ def run_scan_phase(
                 if accept.any():
                     take = accept[sub_sid[cur]]  # rows of accepted shards
                     rows = np.nonzero(take)[0]
-                    buf_k[cur[rows]] = np.asarray(out.keys)[rows]
-                    buf_v[cur[rows]] = np.asarray(out.vals)[rows]
-                    buf_c[cur[rows]] = np.asarray(out.count)[rows]
-                    buf_t[cur[rows]] = np.asarray(out.truncated)[rows]
+                    buf_k[cur[rows]] = pull(holder, "scan.rows", out.keys)[rows]
+                    buf_v[cur[rows]] = pull(holder, "scan.rows", out.vals)[rows]
+                    buf_c[cur[rows]] = pull(holder, "scan.rows", out.count)[rows]
+                    buf_t[cur[rows]] = pull(holder, "scan.rows", out.truncated)[rows]
                     pending &= ~accept
                 if not pending.any():
                     holder._scan_retries += retried
@@ -819,8 +827,8 @@ def execute_scan(holder, lo, hi, cap: int = 128, max_retries: int = 8) -> ScanOu
     """One batched scan round: per query the ≤ ``cap`` smallest keys in
     ``[lo_i, hi_i)``, ascending, stitched across shards in key order.  The
     shared body behind ``ABTree.scan_round`` and ``ABForest.scan_round``."""
-    lo = np.atleast_1d(np.asarray(lo, np.int64))
-    hi = np.atleast_1d(np.asarray(hi, np.int64))
+    lo = np.atleast_1d(np.asarray(pull(holder, "plan", lo), np.int64))
+    hi = np.atleast_1d(np.asarray(pull(holder, "plan", hi), np.int64))
     assert lo.shape == hi.shape and lo.ndim == 1
     k_, v_, c_, t_ = scan_lanes(
         holder, lo, hi, cap, n_scan_ops=int(lo.size), max_retries=max_retries
@@ -872,12 +880,12 @@ def scan_stream_pages(holder, cur: int, hi: int, cap: int):
         s = int(np.searchsorted(holder._splits, cur, side="right"))
         s_hi = min(hi, holder._bounds[s + 1])
         out = holder.scan_round([cur], [s_hi], cap=cap)
-        n = int(np.asarray(out.count)[0])
-        ks = np.asarray(out.keys)[0, :n]
-        vs = np.asarray(out.vals)[0, :n]
+        n = int(pull(holder, "scan.rows", out.count)[0])
+        ks = pull(holder, "scan.rows", out.keys)[0, :n]
+        vs = pull(holder, "scan.rows", out.vals)[0, :n]
         for k, v in zip(ks.tolist(), vs.tolist()):
             yield int(k), int(v)
-        if bool(np.asarray(out.truncated)[0]):
+        if bool(pull(holder, "scan.rows", out.truncated)[0]):
             cur = int(ks[-1]) + 1
         else:
             cur = s_hi  # shard exhausted: jump to the next shard's range
@@ -910,7 +918,7 @@ def _combine_apply(holder, ops_sw, keys_sw, vals_sw):
     ks, arrival, leaf_ids, slot, res, results, found = pack
     rec = _rec(holder)
     if rec.enabled:
-        rec.note_elim(_elim_note(ops_sw, ks, arrival, res))
+        rec.note_elim(_elim_note(holder, ops_sw, ks, arrival, res))
     with tr.span("apply") as sp:
         holder.stacked, deferred = _v_apply(
             holder.stacked, holder.cfg, ks, arrival, leaf_ids, slot, res
@@ -941,9 +949,9 @@ def _occ_round(holder, ops_sw, keys_sw, vals_sw):
     width, and already-satisfied lanes never re-enter the search phase.
     ``holder.subround_hook`` fires after every executed sub-round — the
     durable layer's per-update flush+fence discipline."""
-    on = np.asarray(ops_sw)
-    kn = np.asarray(keys_sw)
-    vn = np.asarray(vals_sw)
+    on = pull(holder, "plan", ops_sw)
+    kn = pull(holder, "plan", keys_sw)
+    vn = pull(holder, "plan", vals_sw)
     n_s, w = on.shape
     rank = np.stack([_duplicate_ranks(on[s], kn[s]) for s in range(n_s)])
     # per-shard sub-round budget: rank r of a real op executes in
@@ -985,8 +993,8 @@ def _occ_round(holder, ops_sw, keys_sw, vals_sw):
                 jnp.asarray(sub_keys, KEY_DTYPE),
                 jnp.asarray(sub_vals, VAL_DTYPE),
             )
-        results[s_idx, pos] = np.asarray(sub_res)[s_idx, slot]
-        found[s_idx, pos] = np.asarray(sub_found)[s_idx, slot]
+        results[s_idx, pos] = pull(holder, "answers", sub_res)[s_idx, slot]
+        found[s_idx, pos] = pull(holder, "answers", sub_found)[s_idx, slot]
         if reg is not None:
             reg.inc("occ_subrounds", int(active.sum()))
         st = holder.stacked
@@ -1013,13 +1021,14 @@ def _drain_deferred(holder, ks, final_vals, arrival, deferred):
     until none remain (all shards per wave).  Returns the pass count."""
     guard = 0
     reg = _metrics(holder)
-    while bool(jnp.any(deferred)):
+    while bool(pull(holder, "deferred", jnp.any(deferred))):
         guard += 1
         assert guard < 512 * holder.cfg.max_height, "split loop diverged"
         if reg is not None:
             reg.inc("retry_passes")
-        uniq = np.asarray(
-            _v_overfull(holder.stacked, holder.cfg, ks, deferred, holder.narrow)
+        uniq = pull(
+            holder, "overfull",
+            _v_overfull(holder.stacked, holder.cfg, ks, deferred, holder.narrow),
         )
         per_shard = [row[row != INT_MAX].astype(np.int32) for row in uniq]
         if any(r.size for r in per_shard):
@@ -1043,9 +1052,9 @@ def _split_cascade(holder, ids_per_shard: List[np.ndarray]):
         guard += 1
         assert guard < 512 * holder.cfg.max_height * n_s, "split cascade diverged"
         st = holder.stacked
-        size = np.asarray(st.size)
-        parent = np.asarray(st.parent)
-        alloc = np.asarray(st.alloc)
+        size = pull(holder, "split", st.size)
+        parent = pull(holder, "split", st.parent)
+        alloc = pull(holder, "split", st.alloc)
         ready_rows: List[np.ndarray] = []
         blocked_rows: List[List[int]] = []
         for s in range(n_s):
@@ -1119,12 +1128,12 @@ def _fix_underfull_all(holder):
             "underfull loop diverged"
         )
         st = holder.stacked
-        alloc = np.asarray(st.alloc)
-        size = np.asarray(st.size)
-        parent = np.asarray(st.parent)
-        level = np.asarray(st.level)
-        is_leaf = np.asarray(st.is_leaf)
-        root = np.asarray(st.root)
+        alloc = pull(holder, "underfull", st.alloc)
+        size = pull(holder, "underfull", st.size)
+        parent = pull(holder, "underfull", st.parent)
+        level = pull(holder, "underfull", st.level)
+        is_leaf = pull(holder, "underfull", st.is_leaf)
+        root = pull(holder, "underfull", st.root)
         sel_rows: List[np.ndarray] = []
         any_wave = False
         want_shrink = False
@@ -1210,18 +1219,14 @@ def execute_plan(holder, plan: RoundPlan) -> RoundOutput:
     tr = _tr(holder)
     reg = _metrics(holder)
     with tr.span("round", lanes=bsz, shards=n_shards):
-        ops_np = np.asarray(plan.ops)
-        keys_np = np.asarray(plan.keys)
-        vals_np = np.asarray(plan.vals)
+        ops_np = pull(holder, "plan", plan.ops)
+        keys_np = pull(holder, "plan", plan.keys)
+        vals_np = pull(holder, "plan", plan.vals)
         # host mirror of elimination.lane_masks: classifying 256 lanes is
         # a handful of numpy compares, not worth five op-by-op dispatches
         # on the round's critical path.
-        is_range = ops_np == int(elim.OP_RANGE)
-        is_point = (
-            (ops_np == int(elim.OP_FIND))
-            | (ops_np == int(elim.OP_INSERT))
-            | (ops_np == int(elim.OP_DELETE))
-        )
+        is_range = ops_np == OP_RANGE
+        is_point = (ops_np == OP_FIND) | (ops_np == OP_INSERT) | (ops_np == OP_DELETE)
 
         results = np.full((bsz,), int(NOTFOUND), np.int64)
         found = np.zeros((bsz,), bool)
@@ -1231,8 +1236,8 @@ def execute_plan(holder, plan: RoundPlan) -> RoundOutput:
         scan_out = None
         if plan.has_range:
             rl = np.nonzero(is_range)[0]
-            lo_np = np.asarray(plan.lo)[rl]
-            hi_np = np.asarray(plan.hi)[rl]
+            lo_np = pull(holder, "plan", plan.lo)[rl]
+            hi_np = pull(holder, "plan", plan.hi)[rl]
             k_, v_, c_, t_ = scan_lanes(
                 holder, lo_np, hi_np, plan.scan_cap, n_scan_ops=plan.n_range
             )
@@ -1286,8 +1291,8 @@ def execute_plan(holder, plan: RoundPlan) -> RoundOutput:
                 jnp.asarray(keys_sw, KEY_DTYPE),
                 jnp.asarray(vals_sw, VAL_DTYPE),
             )
-            results[pl] = np.asarray(res_sw)[shard, slot]
-            found[pl] = np.asarray(fnd_sw)[shard, slot]
+            results[pl] = pull(holder, "answers", res_sw)[shard, slot]
+            found[pl] = pull(holder, "answers", fnd_sw)[shard, slot]
 
         rec = _rec(holder)
         if rec.enabled:
@@ -1328,8 +1333,8 @@ def execute_scan_delete(holder, lo, hi, cap: int = 128, max_retries: int = 8) ->
     next chunk (the one-fused-round-per-chunk sweep contract of
     ``SessionIndex``).  Returns the pre-delete ``ScanOutput`` (the evicted
     keys/values)."""
-    lo = np.atleast_1d(np.asarray(lo, np.int64))
-    hi = np.atleast_1d(np.asarray(hi, np.int64))
+    lo = np.atleast_1d(np.asarray(pull(holder, "plan", lo), np.int64))
+    hi = np.atleast_1d(np.asarray(pull(holder, "plan", hi), np.int64))
     assert lo.shape == hi.shape and lo.ndim == 1
     tr = _tr(holder)
     reg = _metrics(holder)
@@ -1369,8 +1374,8 @@ def execute_scan_delete(holder, lo, hi, cap: int = 128, max_retries: int = 8) ->
             if rec.enabled:
                 slot = np.empty(del_keys.size, np.int64)
                 slot[order] = slot_sorted
-                del_res = np.asarray(res_sw)[shard, slot]
-                del_fnd = np.asarray(fnd_sw)[shard, slot]
+                del_res = pull(holder, "answers", res_sw)[shard, slot]
+                del_fnd = pull(holder, "answers", fnd_sw)[shard, slot]
         if rec.enabled:
             n_r = int(lo.size)
             n_d = int(del_keys.size)

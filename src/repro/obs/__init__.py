@@ -9,7 +9,11 @@ program — the overhead guarantee the spy tests pin):
     retry → rebalance``, plus occ sub-rounds, structural waves, router
     pack/stitch, journal flushes, manifest commits, serve ticks).  Fences
     with ``jax.block_until_ready`` ONLY when enabled; disabled it is a
-    single attribute check returning a shared no-op span.
+    single attribute check returning a shared no-op span.  While a
+    ``jax.profiler`` session runs, every span is also a
+    ``repro.<name>`` profiler annotation.  ``pull`` is the engine's one
+    device→host transfer: it counts ``host_syncs`` / ``d2h_bytes`` in the
+    holder's registry and annotates ``repro.pull.<site>``.
   * :mod:`repro.obs.metrics` — ``MetricsRegistry``: counters / gauges /
     histograms with optional per-shard attribution, one queryable
     ``snapshot()`` absorbing the engine's scattered counter surfaces
@@ -36,13 +40,14 @@ from repro.obs.metrics import (
     engine_collector,
 )
 from repro.obs.recorder import NULL_RECORDER, Recorder
-from repro.obs.tracer import NULL_TRACER, Tracer
+from repro.obs.tracer import NULL_TRACER, Tracer, pull
 
 __all__ = [
     "MetricsRegistry",
     "RegistryBackedCounters",
     "Tracer",
     "NULL_TRACER",
+    "pull",
     "Recorder",
     "NULL_RECORDER",
     "engine_collector",

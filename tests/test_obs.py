@@ -76,6 +76,185 @@ def test_null_tracer_span_is_shared_noop():
 
 
 # ---------------------------------------------------------------------------
+# device→host pulls and the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+class _ConversionSpy:
+    """Counts the device arrays whose data the backend hands to the host,
+    each once, however the conversion is asked for: ``int``/``bool``/
+    ``tolist`` (and numpy off the CPU) reach the backend's fetch through
+    ``_value``; numpy on the CPU takes the buffer protocol.  Once is what
+    a copying backend (TPU) moves: it keeps the first fetch's host copy
+    and serves every later conversion of that array from it."""
+
+    def __init__(self, monkeypatch):
+        from jax._src.array import ArrayImpl
+
+        self.n = self.bytes = 0
+        self._seen = {}  # id -> array, held so that no id is reused
+        fetch, buffer = ArrayImpl._single_device_array_to_np_array_did_copy, ArrayImpl.__buffer__
+
+        def on_fetch(arr):
+            self._count(arr)
+            return fetch(arr)
+
+        def on_buffer(arr, flags):
+            self._count(arr)
+            return buffer(arr, flags)
+
+        monkeypatch.setattr(ArrayImpl, "_single_device_array_to_np_array_did_copy", on_fetch)
+        monkeypatch.setattr(ArrayImpl, "__buffer__", on_buffer)
+
+    def _count(self, arr):
+        if id(arr) not in self._seen:
+            self._seen[id(arr)] = arr
+            self.n += 1
+            self.bytes += arr.nbytes
+
+
+def _pull_round(seed=5):
+    """A prefill and one mixed round (inserts that split leaves, deletes
+    that leave nodes underfull, range and find lanes)."""
+    rng = np.random.default_rng(seed)
+    keys = rng.choice(10**5, 800, replace=False).astype(np.int64)
+    prefill = [
+        (np.full(200, OP_INSERT, np.int32), keys[i : i + 200], keys[i : i + 200])
+        for i in range(0, 600, 200)
+    ]
+    from repro.core import OP_DELETE, OP_RANGE
+
+    ops = np.concatenate([
+        np.full(150, OP_INSERT), np.full(150, OP_DELETE),
+        np.full(4, OP_RANGE), np.full(8, OP_FIND),
+    ]).astype(np.int32)
+    ks = np.concatenate([keys[600:750], keys[:150], keys[300:304], keys[400:408]])
+    vs = np.concatenate([ks[:150], np.zeros(150, np.int64), np.full(4, 5000),
+                         np.zeros(8, np.int64)])
+    return prefill, (ops, ks, vs)
+
+
+@pytest.mark.parametrize("holder", ["ABTree", "DurableABTree"])
+def test_pull_counters_match_every_device_to_host_conversion(monkeypatch, tmp_path, holder):
+    """One round moves ``host_syncs`` / ``d2h_bytes`` by exactly the
+    conversions and bytes a spy on ``jax.Array`` sees.  The measured
+    holder is the second of two identical ones, so every shape is
+    compiled before the spy goes in (lowering embeds constants, which is
+    a conversion of its own)."""
+    from repro.core import DurableABTree
+
+    def build(i):
+        if holder == "ABTree":
+            return ABTree(CFG)
+        return DurableABTree(str(tmp_path / f"j{i}"), CFG, snapshot_every=4)
+
+    prefill, mixed = _pull_round()
+    for i in range(2):
+        t = build(i)
+        for batch in prefill:
+            t.apply_round(*batch)
+        m = t.metrics
+        before = {k: m.value(k) for k in ("host_syncs", "d2h_bytes", "split_waves",
+                                           "underfull_waves", "scans")}
+        if i:
+            spy = _ConversionSpy(monkeypatch)
+        t.apply_round(*mixed)
+    d = {k: m.value(k) - v for k, v in before.items()}
+    assert d["split_waves"] and d["underfull_waves"] and d["scans"]  # every loop ran
+    assert d["host_syncs"] == spy.n > 0
+    assert d["d2h_bytes"] == spy.bytes
+    monkeypatch.undo()
+    st = t.stats()  # the counters as they stood before its own pull
+    assert (st["host_syncs"], st["d2h_bytes"]) == (before["host_syncs"] + d["host_syncs"],
+                                                   before["d2h_bytes"] + d["d2h_bytes"])
+    if holder == "DurableABTree":
+        t.close()
+
+
+def test_pull_passes_host_values_through_uncounted():
+    from repro.obs import pull
+
+    reg = MetricsRegistry()
+    holder = type("H", (), {"metrics": reg})()
+    assert pull(holder, "x", [1, 2]).tolist() == [1, 2]
+    assert pull(holder, "x", np.arange(3)).tolist() == [0, 1, 2]
+    assert reg.value("host_syncs") == 0
+    out = pull(holder, "x", jnp.arange(4, dtype=jnp.int32))
+    assert isinstance(out, np.ndarray) and out.tolist() == [0, 1, 2, 3]
+    assert (reg.value("host_syncs"), reg.value("d2h_bytes")) == (1, 16)
+    assert pull(None, "x", jnp.ones(2)).tolist() == [1.0, 1.0]  # no registry: still a pull
+
+
+def test_pull_counts_an_array_once():
+    """A repeated pull of one array reads its host copy: one sync, its
+    bytes once.  An equal array is another transfer, and an array's entry
+    goes with it."""
+    import gc
+
+    from repro.obs import pull
+    from repro.obs import tracer as T
+
+    reg = MetricsRegistry()
+    holder = type("H", (), {"metrics": reg})()
+    x = jnp.arange(8, dtype=jnp.int32)
+    assert pull(holder, "x", x).tolist() == pull(holder, "y", x).tolist() == list(range(8))
+    assert (reg.value("host_syncs"), reg.value("d2h_bytes")) == (1, 32)
+    pull(holder, "x", jnp.arange(8, dtype=jnp.int32))
+    assert (reg.value("host_syncs"), reg.value("d2h_bytes")) == (2, 64)
+    key = id(x)
+    assert key in T._pulled
+    del x
+    gc.collect()
+    assert key not in T._pulled
+
+
+def _xplane_names(log_dir):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"), recursive=True)
+    names = set()
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            names.update(ev.name for ev in line.events)
+    return names
+
+
+@pytest.mark.parametrize("enabled", [False, True], ids=["null_tracer", "tracer"])
+def test_spans_and_pulls_are_profiler_annotations_while_a_session_runs(tmp_path, monkeypatch,
+                                                                       enabled):
+    """Under ``jax.profiler`` a round's trace holds the program's span
+    names and its pulls, whether the span tracer is enabled or not; a
+    disabled tracer still adds no fence.  Outside a session the disabled
+    span is the shared no-op again."""
+    from repro.obs import tracer as T
+
+    t = ABTree(CFG)
+    rng = np.random.default_rng(8)
+    t.apply_round(*_insert_batch(rng))
+    t.tracer = Tracer() if enabled else NULL_TRACER
+    fences = []
+    real = jax.block_until_ready
+    monkeypatch.setattr("repro.obs.tracer.jax.block_until_ready",
+                        lambda x: (fences.append(1), real(x))[1])
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        assert NULL_TRACER.span("x") is not T._NULL_SPAN
+        t.apply_round(*_insert_batch(rng))
+    finally:
+        jax.profiler.stop_trace()
+    names = _xplane_names(str(tmp_path))
+    assert {"repro.round", "repro.search_combine", "repro.apply", "repro.retry",
+            "repro.rebalance"} <= names
+    assert any(n.startswith("repro.pull.") for n in names), sorted(names)
+    assert "repro.pull.answers" in names
+    assert bool(fences) == enabled
+    assert NULL_TRACER.span("a") is NULL_TRACER.span("b") is T._NULL_SPAN
+
+
+# ---------------------------------------------------------------------------
 # flight-recorder overhead contract
 # ---------------------------------------------------------------------------
 

@@ -151,7 +151,7 @@ def test_malformed_lanes_raise():
 
 def test_round_plan_classification():
     plan = build_plan(
-        [OP_NOP, OP_FIND, OP_RANGE, OP_DELETE], [0, 1, 10, 3], [0, 0, 7, 0]
+        [OP_NOP, OP_FIND, OP_RANGE, OP_DELETE], [0, 1, 10, 3], [0, 0, 7, 0], holder=None
     )
     assert plan.has_point and plan.has_range and plan.n_range == 1
     assert np.asarray(plan.is_range).tolist() == [False, False, True, False]
@@ -160,7 +160,7 @@ def test_round_plan_classification():
     assert int(np.asarray(plan.lo)[2]) == 10 and int(np.asarray(plan.hi)[2]) == 17
     # non-range lanes scan the empty interval [EMPTY, EMPTY)
     assert int(np.asarray(plan.lo)[0]) == int(EMPTY)
-    point_only = build_plan([OP_INSERT], [1], [1])
+    point_only = build_plan([OP_INSERT], [1], [1], holder=None)
     assert point_only.has_point and not point_only.has_range
 
 
